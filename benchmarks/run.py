@@ -18,13 +18,16 @@ matrix (3 regimes × {adaptive policy, 3 fixed modes} on the device engine)
 and writes `BENCH_adversarial.json` — the CI tests-adversarial matrix job
 fans one job per regime and gates the per-regime decision counts exactly
 via `benchmarks.check_regression --suite adversarial-<regime>`.
-`--devices N` forces N host devices (XLA flag set **before** jax imports,
-which is why all heavy imports live inside the entry points) and, with
-`--smoke`, runs the sharded-engine + sharded-offload-hybrid cells instead,
-writing `BENCH_sharded.json` — uploaded as an artifact by the CI
-multi-device job and gated there via
-`benchmarks.check_regression --suite sharded` (deterministic per-shard
-transfer-row volume).
+`--devices N` is a CPU rehearsal: it forces `JAX_PLATFORMS=cpu` with N
+virtual host devices (XLA flag set **before** jax imports, which is why all
+heavy imports live inside the entry points) and, with `--smoke`, runs the
+sharded-engine + sharded-offload-hybrid cells instead, writing
+`BENCH_sharded.json` — uploaded as an artifact by the CI multi-device job
+and gated there via `benchmarks.check_regression --suite sharded`
+(deterministic per-shard transfer-row volume).  It never measures a chip;
+`chip_smoke.py --chips 4` runs the sharded path on four real chips.
+The persistent compilation cache follows `JAX_COMPILATION_CACHE_DIR`, or
+else lives in `<checkout>/.jax_cache` (`repro.compile_cache`).
 """
 from __future__ import annotations
 
@@ -34,6 +37,7 @@ import os
 import sys
 import time
 import traceback
+from pathlib import Path
 
 
 def _module_registry():
@@ -126,8 +130,10 @@ def main() -> None:
                          "(hub_burst/delete_heavy/feature_churn) — the CI "
                          "matrix fans one job per regime")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host devices (pre-jax-init); with --smoke, "
-                         "run the sharded cell and write BENCH_sharded.json")
+                    help="CPU rehearsal: force JAX_PLATFORMS=cpu with N "
+                         "virtual host devices (pre-jax-init), never a chip; "
+                         "with --smoke, run the sharded cell and write "
+                         "BENCH_sharded.json")
     ap.add_argument("--out", type=str, default="",
                     help="write the emitted rows as a {rows, wall_s} JSON "
                          "artifact (the nightly CI job uploads "
@@ -140,7 +146,12 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={args.devices}".strip()
         )
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        print(f"--devices {args.devices}: CPU rehearsal on virtual host "
+              "devices, not a chip measurement", file=sys.stderr)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     if args.smoke:
         if args.devices:
             smoke_sharded(args.devices)

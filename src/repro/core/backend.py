@@ -1352,18 +1352,11 @@ class DeviceBackend(StateBackend):
         idx, flt, msk, feat_vals, pallas = jax.device_put(
             (packed.idx, packed.flt, packed.msk, packed.feat_vals, packed.pallas)
         )
-        with warnings.catch_warnings():
-            # donation is a TPU/GPU aliasing optimization; CPU jit ignores it
-            # with a UserWarning per compile — suppress it here (scoped) so
-            # the CPU hot path stays quiet without touching global filters
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable"
-            )
-            hs, as_, ncts = fused_stream_step(
-                self.model, packed.layout, tuple(self.params),
-                tuple(self._h), tuple(self._a), tuple(self._nct),
-                idx, flt, msk, feat_vals, pallas,
-            )
+        hs, as_, ncts = fused_stream_step(
+            self.model, packed.layout, tuple(self.params),
+            tuple(self._h), tuple(self._a), tuple(self._nct),
+            idx, flt, msk, feat_vals, pallas,
+        )
         self._h = list(hs)
         self._a = list(as_)
         self._nct = list(ncts)
@@ -2310,22 +2303,17 @@ class ShardBackend(_StreamMeshMixin, StateBackend):
         idx_rep, msk_rep, feat_vals = jax.device_put(
             (sp.idx_rep, sp.msk_rep, fv), self._rep_sh
         )
-        with warnings.catch_warnings():
-            # donation is a TPU/GPU aliasing optimization; CPU jit ignores it
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable"
-            )
-            # plan-derived halo traffic: each delivered row carries its
-            # old+new previous-layer views (the concatenated halo payload)
-            for l, rows_l in enumerate(sp.comms_rows or ()):
-                self._comms_rows_sent += rows_l
-                self._comms_bytes += rows_l * 2 * int(self._h[l].shape[-1]) * 4
-            hs, as_, ncts = self._step(
-                sp.layout, self.params,
-                tuple(self._h), tuple(self._a), tuple(self._nct),
-                idx_sh, flt_sh, msk_sh, idx_rep, msk_rep, feat_vals, pallas_sh,
-                comms_sh,
-            )
+        # plan-derived halo traffic: each delivered row carries its
+        # old+new previous-layer views (the concatenated halo payload)
+        for l, rows_l in enumerate(sp.comms_rows or ()):
+            self._comms_rows_sent += rows_l
+            self._comms_bytes += rows_l * 2 * int(self._h[l].shape[-1]) * 4
+        hs, as_, ncts = self._step(
+            sp.layout, self.params,
+            tuple(self._h), tuple(self._a), tuple(self._nct),
+            idx_sh, flt_sh, msk_sh, idx_rep, msk_rep, feat_vals, pallas_sh,
+            comms_sh,
+        )
         self._h = list(hs)
         self._a = list(as_)
         self._nct = list(ncts)

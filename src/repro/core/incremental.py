@@ -41,7 +41,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.affected import (
@@ -62,31 +61,31 @@ def with_scratch(x: jax.Array) -> jax.Array:
     return jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)], axis=0)
 
 
+def _pallas_interpret() -> bool:
+    """Pallas runs compiled on a TPU and through its interpreter elsewhere."""
+    return jax.default_backend() != "tpu"
+
+
 def _pallas_delta_scatter(
-    ctx: jax.Array,  # [Ecap, C] signed, mask-scaled
-    raw: jax.Array,  # [Ecap, agg]
+    raw: jax.Array,  # [Epad, agg] signed, mask-scaled, in block-CSR order
     sched: Tuple[jax.Array, jax.Array, jax.Array],  # (perm, dloc, block_rows)
     r_cap: int,
-) -> Tuple[jax.Array, jax.Array]:
-    """Step-1 scatter via the Pallas ``delta_agg`` kernel (one fused
-    [ctx | raw] scatter); schedule was planned host-side in pack_plan."""
+) -> jax.Array:
+    """Step-1 scatter of the aggregation terms via the Pallas ``delta_agg``
+    kernel; the schedule was planned host-side in pack_plan, and the
+    records already stand in its block order (see :func:`_layer_body`)."""
     from repro.kernels.delta_agg import DELTA_BD, DELTA_BE, DELTA_TV, delta_agg
 
-    perm, dloc, brows = sched
-    c = ctx.shape[1]
-    msg = jnp.concatenate([ctx, raw], axis=1)
-    safe = jnp.maximum(perm, 0)
-    m = msg[safe] * (perm >= 0).astype(msg.dtype)[:, None]  # block layout
-    d = m.shape[1]
+    _, dloc, brows = sched
+    d = raw.shape[1]
     dpad = -(-d // DELTA_BD) * DELTA_BD
-    if dpad != d:
-        m = jnp.pad(m, ((0, 0), (0, dpad - d)))
+    m = raw if dpad == d else jnp.pad(raw, ((0, 0), (0, dpad - d)))
     state = jnp.zeros((r_cap, dpad), m.dtype)  # r_cap is pow2 ≥ 16 → tv-aligned
     out = delta_agg(
         m, dloc, brows, state, tv=DELTA_TV, be=DELTA_BE, bd=DELTA_BD,
-        interpret=jax.default_backend() != "tpu",
+        interpret=_pallas_interpret(),
     )
-    return out[:, :c], out[:, c:d]
+    return out[:, :d]
 
 
 def _layer_body(
@@ -136,10 +135,25 @@ def _layer_body(
     f_cap = f_rows.shape[0]
 
     # ---------------- step 1: signed delta messages (Alg.1 l.1-3) -------
-    use = e_use_new[:, None]
-    h_u = jnp.where(use, h_prev_new[e_src], h_prev_old[e_src])
+    if pallas_delta is not None:
+        # put the records in the kernel's block-CSR order before computing
+        # their messages: permuting the narrow record fields is cheap, while
+        # permuting the [E, d] messages would hold a second message-sized
+        # buffer in device memory.  Block pads are masked and row-less.
+        perm = pallas_delta[0]
+        live = perm >= 0
+        safe = jnp.where(live, perm, 0)
+        e_src, e_dst, e_sign, e_use_new, e_w, e_t = (
+            f[safe] for f in (e_src, e_dst, e_sign, e_use_new, e_w, e_t))
+        e_rowidx = jnp.where(live, e_rowidx[safe], r_cap)
+        e_mask = e_mask[safe] & live
+    # one gather per endpoint from the stacked [old | new] table: selecting
+    # between two gathers would hold two [E, d] buffers in device memory
+    h_both = jnp.concatenate([h_prev_old, h_prev_new], axis=0)
+    view = jnp.where(e_use_new, h_prev_old.shape[0], 0)
+    h_u = h_both[e_src + view]
     if model.dest_dependent:
-        h_v = jnp.where(use, h_prev_new[e_dst], h_prev_old[e_dst])
+        h_v = h_both[e_dst + view]
     else:
         # Theorem 1 requires ms_local independent of the destination for
         # unconstrained models — skip the h[dst] halo gather entirely
@@ -153,10 +167,11 @@ def _layer_body(
     raw = raw * scale
 
     # compact scatter into touched-row space (O(affected), not O(V))
+    # (the narrow context column always takes XLA's segment-sum)
+    d_nct = jax.ops.segment_sum(ctx, e_rowidx, num_segments=r_cap + 1)[:r_cap]
     if pallas_delta is not None:
-        d_nct, d_s = _pallas_delta_scatter(ctx, raw, pallas_delta, r_cap)
+        d_s = _pallas_delta_scatter(raw, pallas_delta, r_cap)
     else:
-        d_nct = jax.ops.segment_sum(ctx, e_rowidx, num_segments=r_cap + 1)[:r_cap]
         d_s = jax.ops.segment_sum(raw, e_rowidx, num_segments=r_cap + 1)[:r_cap]
 
     # ---------------- step 2: cbn⁻¹ → delta-agg → cbn (Alg.1 l.4-6) -----
@@ -448,12 +463,12 @@ def sharded_step_fn(model: GNNModel, mesh, axis: str):
 
         sh = P(axis)  # leading shard dim
         rep = P()
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(rep, sh, sh, sh, sh, sh, sh, rep, rep, rep, sh, sh),
             out_specs=(sh, sh, sh),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(params, h_blocks, a_blocks, nct_blocks, idx_sh, flt_sh, msk_sh,
                   idx_rep, msk_rep, feat_vals, pallas_sh, comms_sh)
@@ -521,12 +536,12 @@ def hybrid_layer_step_fn(model: GNNModel, mesh, axis: str):
             return an[None, :ns_cap], nn[None, :ns_cap], hn[None, :ns_cap]
 
         sh = P(axis)
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(), sh, sh, sh, sh, sh, sh, sh, sh),
             out_specs=(sh, sh, sh),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(p, h_old_rows, h_new_rows, a_rows, nct_rows, h_cur_rows,
                   idx_sh, flt_sh, msk_sh)

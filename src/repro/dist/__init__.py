@@ -22,7 +22,8 @@ Usage::
     from repro.dist import activation_sharding
     from repro.launch.steps import make_train_step, shardings_for_cell
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh = shardings_for_cell(cfg, ShapeConfig("tiny", 16, 8, "train"), mesh)
     with activation_sharding(mesh, sh["shcfg"]):
         step = jax.jit(make_train_step(cfg, opt_cfg),

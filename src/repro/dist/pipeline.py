@@ -16,7 +16,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import rotation_perm
@@ -69,11 +68,11 @@ def pipeline_apply(
     )
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run(local_params, xs_all):
         idx = lax.axis_index(stage_axis)

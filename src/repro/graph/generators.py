@@ -28,22 +28,30 @@ def barabasi_albert(
     seed: int = 0,
     undirected: bool = True,
 ) -> CSRGraph:
-    """Preferential-attachment power-law graph with ~m edges per new vertex."""
+    """Preferential-attachment power-law graph with ~m edges per new vertex.
+
+    Linear in ``n``: the attachment pool (every endpoint so far, so a
+    uniform draw from it is degree-proportional) lives in one preallocated
+    buffer, which grows by at most ``2m`` entries per vertex."""
     rng = np.random.default_rng(seed)
-    targets = list(range(m))
-    repeated: list[int] = list(range(m))
-    src_l: list[int] = []
-    dst_l: list[int] = []
+    k = max(n - m, 0)
+    pool = np.empty(m + 2 * m * k, np.int64)
+    pool[:m] = np.arange(m)
+    size = m
+    src = np.empty(m * k, np.int64)
+    dst = np.empty(m * k, np.int64)
+    ne = 0
     for v in range(m, n):
-        chosen = rng.choice(repeated, size=m, replace=True)
-        chosen = np.unique(chosen)
-        for t in chosen:
-            src_l.append(v)
-            dst_l.append(int(t))
-        repeated.extend(chosen.tolist())
-        repeated.extend([v] * len(chosen))
-    src = np.array(src_l, dtype=np.int64)
-    dst = np.array(dst_l, dtype=np.int64)
+        # the same draws as rng.choice(pool[:size], size=m) makes
+        chosen = np.unique(pool[rng.integers(0, size, size=m, dtype=np.int64)])
+        c = chosen.size
+        src[ne:ne + c] = v
+        dst[ne:ne + c] = chosen
+        ne += c
+        pool[size:size + c] = chosen
+        pool[size + c:size + 2 * c] = v
+        size += 2 * c
+    src, dst = src[:ne], dst[:ne]
     if undirected:
         src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
     mask = src != dst

@@ -36,13 +36,14 @@ def _kernel(block_rows_ref, dloc_ref, msg_ref, state_ref, out_ref):
     def _():
         out_ref[...] = state_ref[...]
 
-    dloc = dloc_ref[...].reshape(-1)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (out_ref.shape[0], dloc.shape[0]), 0)
-    onehot = (rows == dloc[None, :]).astype(jnp.float32)
+    dloc = dloc_ref[...]  # [1, be]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (out_ref.shape[0], dloc.shape[1]), 0)
+    onehot = (rows == dloc).astype(jnp.float32)
     msg = msg_ref[...].astype(jnp.float32)
-    out_ref[...] += jnp.dot(onehot, msg, preferred_element_type=jnp.float32).astype(
-        out_ref.dtype
-    )
+    # the one-hot product is a scatter-add: it must keep every bit of the
+    # float32 messages, which a default-precision (bf16-pass) TPU dot drops
+    out_ref[...] += jnp.dot(onehot, msg, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tv", "be", "bd", "interpret"))
@@ -65,7 +66,9 @@ def delta_agg(
         num_scalar_prefetch=1,
         grid=(nd, nb),
         in_specs=[
-            pl.BlockSpec((be, 1), lambda j, i, br: (i, 0)),
+            # lane-major destinations: a [E, 1] column would be tiled to
+            # 128 lanes in HBM, 128x the bytes of the indices themselves
+            pl.BlockSpec((None, 1, be), lambda j, i, br: (i, 0, 0)),
             pl.BlockSpec((be, bd), lambda j, i, br: (i, j)),
             pl.BlockSpec((tv, bd), lambda j, i, br: (br[i], j)),  # state (read)
         ],
@@ -78,4 +81,4 @@ def delta_agg(
         input_output_aliases={3: 0},  # alias state → out (after scalar operand)
         interpret=interpret,
         name="delta_agg",
-    )(block_rows, dst_local[:, None], messages, state)
+    )(block_rows, dst_local.reshape(nb, 1, be), messages, state)

@@ -55,31 +55,24 @@ def prepare_block_csr(
     dstv = dst[valid]
     idxv = np.nonzero(valid)[0]
     assert np.all(np.diff(dstv) >= 0), "dst must be sorted ascending"
+    if not dstv.size:  # empty input
+        pad = np.full(be, -1, np.int32)
+        return pad, pad.copy(), np.zeros(1, np.int32), be
     tiles = dstv // tv
-    perm_parts = []
-    dloc_parts = []
-    block_rows = []
-    for t in np.unique(tiles):
-        sel = idxv[tiles == t]
-        cnt = sel.shape[0]
-        pad = (-cnt) % be
-        perm_parts.append(np.concatenate([sel, np.full(pad, -1, np.int64)]))
-        dl = np.concatenate([dstv[tiles == t] - t * tv, np.full(pad, -1, np.int64)])
-        dloc_parts.append(dl)
-        block_rows.extend([int(t)] * ((cnt + pad) // be))
-    if not perm_parts:  # empty input
-        perm = np.full(be, -1, np.int64)
-        dloc = np.full(be, -1, np.int64)
-        block_rows = [0]
-    else:
-        perm = np.concatenate(perm_parts)
-        dloc = np.concatenate(dloc_parts)
-    return (
-        perm.astype(np.int32),
-        dloc.astype(np.int32),
-        np.asarray(block_rows, np.int32),
-        perm.shape[0],
-    )
+    # sorted dst → each tile's records are one contiguous run; each run is
+    # padded to a whole number of edge blocks
+    tile_ids, starts, counts = np.unique(tiles, return_index=True,
+                                         return_counts=True)
+    padded = counts + (-counts) % be
+    offsets = np.cumsum(padded) - padded
+    e_pad = int(padded.sum())
+    pos = np.arange(dstv.size) + np.repeat(offsets - starts, counts)
+    perm = np.full(e_pad, -1, np.int32)
+    dloc = np.full(e_pad, -1, np.int32)
+    perm[pos] = idxv
+    dloc[pos] = dstv - tiles * tv
+    block_rows = np.repeat(tile_ids, padded // be).astype(np.int32)
+    return perm, dloc, block_rows, e_pad
 
 
 def _kernel(block_rows_ref, dloc_ref, msg_ref, out_ref):

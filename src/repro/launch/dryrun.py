@@ -41,7 +41,9 @@ from repro.train.optimizer import OptConfig
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
-# v5e hardware constants (per chip)
+# v5e per-chip constants of the dry-run cost model only: the compile-only
+# sweep here has no device to ask, and nothing on the engine's chip path
+# (chip_smoke.py, repro.core, repro.serve) reads them
 PEAK_FLOPS = 197e12  # bf16
 HBM_BW = 819e9
 ICI_BW = 50e9  # per link

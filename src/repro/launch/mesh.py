@@ -29,12 +29,15 @@ def make_production_mesh(*, multi_pod: bool = False, pipeline_stages: int = 0):
                  shape[data_idx] // pipeline_stages, shape[-1])
         axes = (*axes[:data_idx], "stage", "data", "model")
     n = int(np.prod(shape))
+    # the steps constrain activations with with_sharding_constraint, which
+    # needs Auto axes (make_mesh defaults to Explicit)
+    auto = (jax.sharding.AxisType.Auto,) * len(shape)
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, auto)
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}; have {len(devices)} — run under "
             f'XLA_FLAGS="--xla_force_host_platform_device_count={n}" (dryrun.py sets this)'
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, auto, devices=devices[:n])

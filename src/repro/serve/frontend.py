@@ -74,9 +74,12 @@ class StaleVersionError(ReadRejectedError):
     """Read pinned below the retained undo-history floor."""
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class ReadTicket:
-    """One embedding-read query: global vertex ids pinned to a version."""
+    """One embedding-read query: global vertex ids pinned to a version.
+
+    Tickets compare by identity: the pending queue removes the one served,
+    and field equality over the ``rows`` array has no truth value."""
 
     rows: np.ndarray  # int64 global vertex ids (as submitted)
     version: int  # pinned version

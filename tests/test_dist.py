@@ -63,7 +63,8 @@ def _run_sub(body: str) -> str:
 def test_pipeline_matches_sequential_subprocess():
     print(_run_sub("""
     from repro.dist.pipeline import pipeline_apply, sequential_reference
-    mesh = jax.make_mesh((4, 2), ("stage", "model"))
+    mesh = jax.make_mesh((4, 2), ("stage", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     S, D = 4, 16
     key = jax.random.PRNGKey(0)
     params = {"w": jax.random.normal(key, (S, D, D)) * 0.3}
@@ -96,7 +97,8 @@ def test_sharded_train_step_subprocess():
         num_layers=2, d_model=32, d_ff=64, num_heads=4, num_kv_heads=2,
         head_dim=8, vocab_size=128,
     )
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shape = ShapeConfig("tiny", 16, 8, "train")
     sh = shardings_for_cell(cfg, shape, mesh)
     step = make_train_step(cfg, OptConfig(warmup_steps=1, stable_steps=10, decay_steps=1))
@@ -135,7 +137,8 @@ def test_serve_step_sharded_subprocess():
         num_layers=2, d_model=32, d_ff=64, num_heads=4, num_kv_heads=2,
         head_dim=8, vocab_size=128,
     )
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shape = ShapeConfig("tinydec", 64, 8, "decode")
     sh = shardings_for_cell(cfg, shape, mesh)
     step = make_serve_step(cfg)

@@ -32,7 +32,8 @@ def _tiny_cfg():
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _specs(sharding_tree):

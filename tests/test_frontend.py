@@ -135,6 +135,25 @@ def test_reads_interleave_with_pending_writes():
     assert fr.stats().staleness_batches == len(wl.batches) - 1
 
 
+def test_older_pin_queued_behind_fresh_reads_is_served():
+    """A read pinned a version back, queued behind fresh reads of other
+    rows, is served first (lower pin) out of the middle of the queue."""
+    model = make_model("gcn")
+    x, wl = _mk_stream(num_batches=2)
+    cfg = _cfg(model, wl, x)
+    rows = np.arange(0, wl.base.n, 7)
+    refs = _serial_reference("device", cfg, wl, rows)
+
+    fr = ServingFrontend(create_engine("device", cfg))
+    fr.apply_batch(wl.batches[0])
+    fresh = [fr.submit_read(rows[i::3]) for i in range(3)]
+    pinned = fr.submit_read(rows, version=0)
+    fr.drain()
+    np.testing.assert_array_equal(pinned.value(), refs[0])
+    for i, t in enumerate(fresh):
+        np.testing.assert_array_equal(t.value(), refs[1][i::3])
+
+
 # ---------------------------------------------------------------------- #
 # admission control / backpressure
 # ---------------------------------------------------------------------- #

@@ -105,6 +105,32 @@ def test_generators_shapes():
     assert abs(g2.num_edges / 200 - 6.0) < 2.0
 
 
+def _barabasi_albert_list_loop(n, m, seed):
+    """The original quadratic generator: rng.choice over a growing list."""
+    rng = np.random.default_rng(seed)
+    repeated = list(range(m))
+    src, dst = [], []
+    for v in range(m, n):
+        chosen = np.unique(rng.choice(repeated, size=m, replace=True))
+        src += [v] * len(chosen)
+        dst += chosen.tolist()
+        repeated += chosen.tolist() + [v] * len(chosen)
+    src, dst = np.array(src, np.int64), np.array(dst, np.int64)
+    return np.concatenate([src, dst]), np.concatenate([dst, src])
+
+
+@pytest.mark.parametrize("n,m,seed", [(300, 4, 0), (400, 7, 3)])
+def test_barabasi_albert_matches_list_loop(n, m, seed):
+    """The preallocated-buffer generator draws exactly what the list loop
+    drew, so every seeded graph (and every counter gate built on one) is
+    unchanged."""
+    src, dst = _barabasi_albert_list_loop(n, m, seed)
+    ref = CSRGraph.from_edges(n, src, dst)
+    got = barabasi_albert(n, m=m, seed=seed)
+    for a, b in zip(ref.edges_by_dst(), got.edges_by_dst()):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_stream_consistency():
     g = make_graph("powerlaw", 200, avg_degree=6, seed=0)
     wl = make_stream(g, num_batches=5, batch_edges=20, delete_frac=0.3, seed=2)
