@@ -18,8 +18,7 @@ The gate watches a small **metric matrix** (``SPECS``), not a single cell:
   compact row sets or remap tables regressed;
 * ``fig7/smoke/gcn/frontend_reads_served`` / ``_staleness_batches`` — the
   serving front-end's deterministic read counters from its fixed
-  interleaving schedule (ISSUE 6), gated exactly; the read-latency rows
-  stay non-blocking telemetry.
+  interleaving schedule, gated exactly.
 * ``fig7/smoke/gcn/cache_staged_bytes`` + ``cache_hit_rows`` /
   ``cache_miss_rows`` / ``cache_evictions`` — the hot-row cache set
   (ISSUE 8): the staged-bytes row carries the uncached/cached reduction
@@ -95,8 +94,7 @@ SPECS = (
     # serving front-end read counters (ISSUE 6): the smoke cell's read
     # schedule is deterministic (one fresh + one two-back pinned read per
     # batch once version ≥ 2 → 10 served, cumulative staleness 8), so both
-    # counters gate BLOCKING and exactly; the companion read_p99 latency
-    # row is telemetry and never gated
+    # counters gate BLOCKING and exactly
     MetricSpec(name="fig7/smoke/gcn/frontend_reads_served", kind="exact"),
     MetricSpec(name="fig7/smoke/gcn/frontend_staleness_batches",
                kind="exact"),

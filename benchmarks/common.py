@@ -122,8 +122,7 @@ def emit_stream_stats(prefix: str, ss, expect_prefetch: int = None,
       expectation for the CI exact gate);
     * ``<prefix>_reads_served`` / ``<prefix>_staleness_batches`` — the
       serving front-end's deterministic read counters (only when
-      ``expect_reads`` is given; CI exact gate), plus the non-gated
-      ``<prefix>_read_p99`` latency row.
+      ``expect_reads`` is given; CI exact gate).
     """
     d = ss.as_dict()
     emit(f"{prefix}_stream_wall", d["wall_s"] * 1e6,
@@ -139,6 +138,3 @@ def emit_stream_stats(prefix: str, ss, expect_prefetch: int = None,
              f"expect_{expect_reads}")
         emit(f"{prefix}_staleness_batches", float(d["staleness_batches"]),
              f"expect_{expect_staleness}")
-        emit(f"{prefix}_read_p99", d["read_p99_s"] * 1e6,
-             f"p50_{d['read_p50_s'] * 1e6:.0f}us_rejected_"
-             f"{d['reads_rejected']}")

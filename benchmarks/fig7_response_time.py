@@ -3,7 +3,8 @@ across six GNN models × methods, in-memory processing.
 
 Also hosts the serving-frontend cells (ISSUE 6): the smoke job's
 deterministic read-counter cell (`smoke_frontend`, CI-gated exactly) and
-the full sweep's latency-vs-throughput curve (`run_serving`, telemetry)."""
+the full sweep's read-pressure-vs-throughput curve (`run_serving`,
+telemetry)."""
 from __future__ import annotations
 
 from benchmarks.common import (
@@ -217,8 +218,7 @@ def smoke_frontend(model, params, wl, x):
     existing 6-batch stream on the offload engine, deterministic schedule —
     before batch i one read pinned at the current version i plus, once
     version ≥ 2, one pinned at i-2.  Over 6 batches that is 10 served reads
-    with cumulative staleness 8 (4 × 2 batches), both CI-gated exactly;
-    read_p99 is latency telemetry (never gated)."""
+    with cumulative staleness 8 (4 × 2 batches), both CI-gated exactly."""
     import numpy as np
 
     from repro.serve import ServingFrontend, create_engine, EngineConfig
@@ -432,11 +432,11 @@ def run(quick: bool = True):
 
 
 def run_serving(x, wl):
-    """Latency-vs-throughput serving cells (ISSUE 6, full sweep only): the
-    gcn offload engine under increasing read pressure — r reads per update
-    batch, each pinned one version back — reporting update throughput
-    against read p50/p99.  Telemetry rows (timing on a shared CI host is
-    noise); the deterministic read counters are gated in the *smoke* cell."""
+    """Read-pressure-vs-throughput serving cells (full sweep only):
+    the gcn offload engine under increasing read pressure — r reads per
+    update batch, each pinned one version back — reporting update
+    throughput.  Telemetry rows (timing on a shared CI host is noise); the
+    deterministic read counters are gated in the *smoke* cell."""
     import numpy as np
 
     from repro.serve import EngineConfig, ServingFrontend, create_engine
@@ -463,7 +463,5 @@ def run_serving(x, wl):
         fr.drain()
         ss = fr.stats()
         thpt = upd_per_batch * len(wl.batches) / max(ss.wall_s, 1e-9)
-        emit(f"fig7/serving/gcn/reads{r}_read_p99", ss.read_p99_s * 1e6,
-             f"p50_{ss.read_p50_s * 1e6:.0f}us")
         emit(f"fig7/serving/gcn/reads{r}_throughput", ss.wall_s * 1e6,
              f"{thpt:.0f}_upd_per_s")

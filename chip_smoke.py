@@ -49,9 +49,6 @@ sys.path.insert(0, str(ROOT / "src"))
 MATMUL_PRECISION = "highest"
 TOL = 1e-3
 
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-
 
 @dataclasses.dataclass(frozen=True)
 class SmokeConfig:
@@ -90,32 +87,6 @@ class PhaseResult:
     reads_served: int
     state_bytes: int
     bytes_in_use: Optional[List[Optional[int]]] = None
-
-
-class CompileCounter:
-    """Counts XLA executables built (compiled or read from the persistent
-    cache) and persistent-cache hits, through jax.monitoring."""
-
-    def __init__(self) -> None:
-        import jax
-
-        self.compiles = 0
-        self.cache_hits = 0
-        self._jax = jax
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, duration: float, **kw) -> None:
-        if event == BACKEND_COMPILE_EVENT:
-            self.compiles += 1
-
-    def _on_event(self, event: str, **kw) -> None:
-        if event == CACHE_HIT_EVENT:
-            self.cache_hits += 1
-
-    def close(self) -> None:
-        self._jax.monitoring.unregister_event_duration_listener(self._on_duration)
-        self._jax.monitoring.unregister_event_listener(self._on_event)
 
 
 def build_workload(cfg: SmokeConfig, log: Callable[[str], None] = print) -> Workload:
@@ -174,95 +145,88 @@ def _bytes_in_use(devices) -> List[Optional[int]]:
 
 def run_phase(name: str, backend: str, wl: Workload, comms=None,
               num_shards: Optional[int] = None,
-              counter: Optional[CompileCounter] = None,
               log: Callable[[str], None] = print) -> PhaseResult:
     """Drive one backend through create_engine → StreamOrchestrator →
     ServingFrontend over the workload's stream, then compare its final
     embeddings and served reads with the references."""
     import jax
 
+    from repro.obs import TALLY
     from repro.serve import EngineConfig, ServingFrontend, create_engine
 
     cfg = wl.cfg
-    own_counter = counter is None
-    counter = counter or CompileCounter()
-    try:
-        t0 = time.perf_counter()
-        eng = create_engine(backend, EngineConfig(
-            model=wl.model, graph=wl.stream.base, x=wl.x, params=wl.params,
-            comms=comms, num_shards=num_shards))
-        fe = ServingFrontend(eng)
-        log(f"[{name}] engine up: state_bytes={eng.state_bytes()} "
-            f"init_s={time.perf_counter() - t0:.3f}")
-        rng = np.random.default_rng(cfg.seed + 1)
-        tickets = []
+    t0 = time.perf_counter()
+    eng = create_engine(backend, EngineConfig(
+        model=wl.model, graph=wl.stream.base, x=wl.x, params=wl.params,
+        comms=comms, num_shards=num_shards))
+    fe = ServingFrontend(eng)
+    log(f"[{name}] engine up: state_bytes={eng.state_bytes()} "
+        f"init_s={time.perf_counter() - t0:.3f}")
+    rng = np.random.default_rng(cfg.seed + 1)
+    tickets = []
 
-        def submit() -> None:
-            for r in range(cfg.reads):
-                pin = max(fe.version - 1, 0) if r == cfg.reads - 1 else None
-                rows = rng.choice(cfg.n, size=cfg.read_rows, replace=False)
-                tickets.append(fe.submit_read(rows, version=pin))
+    def submit() -> None:
+        for r in range(cfg.reads):
+            pin = max(fe.version - 1, 0) if r == cfg.reads - 1 else None
+            rows = rng.choice(cfg.n, size=cfg.read_rows, replace=False)
+            tickets.append(fe.submit_read(rows, version=pin))
 
-        compiles_after = 0
-        for i, batch in enumerate(wl.stream.batches):
-            submit()
-            c0 = counter.compiles
-            bs = fe.apply_batch(batch)
-            new = counter.compiles - c0
-            if i > 0:
-                compiles_after += new
-            log(f"[{name}] batch {i}: inc_edges={bs.inc_edges} "
-                f"out_rows={bs.out_vertices} compiles={new} "
-                f"plan_s={bs.plan_time_s:.6f} step_s={bs.exec_time_s:.6f} "
-                "(one-off smoke timing)")
+    compiles_after = 0
+    for i, batch in enumerate(wl.stream.batches):
         submit()
-        fe.drain()
-        final = np.asarray(eng.embeddings)
-        bytes_in_use = _bytes_in_use(jax.devices())
-        state_bytes = eng.state_bytes()
-        del fe, eng
+        c0 = TALLY.compiles
+        bs = fe.apply_batch(batch)
+        new = TALLY.compiles - c0
+        if i > 0:
+            compiles_after += new
+        log(f"[{name}] batch {i}: inc_edges={bs.inc_edges} "
+            f"out_rows={bs.out_vertices} compiles={new} "
+            f"plan_s={bs.plan_time_s:.6f} step_s={bs.exec_time_s:.6f} "
+            "(one-off smoke timing)")
+    submit()
+    fe.drain()
+    final = np.asarray(eng.embeddings)
+    bytes_in_use = _bytes_in_use(jax.devices())
+    state_bytes = eng.state_bytes()
+    del fe, eng
 
-        checks = [(final, wl.refs[cfg.batches])]
-        for t in tickets[-cfg.reads:]:
-            checks.append((t.value(), wl.refs[t.version][t.rows]))
-        scale = max(float(np.abs(ref).max()) for _, ref in checks)
-        max_abs = max(float(np.abs(got - ref).max()) for got, ref in checks)
-        finite = all(np.isfinite(got).all() for got, _ in checks)
-        shapes = all(got.shape == ref.shape for got, ref in checks)
-        n_reads = cfg.reads * (cfg.batches + 1)
-        served = sum(t.result is not None for t in tickets)
-        ok = (finite and shapes and served == n_reads
-              and max_abs <= TOL * max(1.0, scale))
-        res = PhaseResult(name, max_abs, max_abs / max(scale, 1e-30), ok,
-                          compiles_after, served, state_bytes, bytes_in_use)
-        log(f"[{name}] max_abs_err={res.max_abs!r} max_rel_err={res.max_rel!r} "
-            f"tol={TOL}*max(1,{scale!r}) reads_served={served}/{n_reads} "
-            f"pinned_version={tickets[-1].version} "
-            f"compiles_after_warmup={compiles_after} ok={ok}")
-        return res
-    finally:
-        if own_counter:
-            counter.close()
+    checks = [(final, wl.refs[cfg.batches])]
+    for t in tickets[-cfg.reads:]:
+        checks.append((t.value(), wl.refs[t.version][t.rows]))
+    scale = max(float(np.abs(ref).max()) for _, ref in checks)
+    max_abs = max(float(np.abs(got - ref).max()) for got, ref in checks)
+    finite = all(np.isfinite(got).all() for got, _ in checks)
+    shapes = all(got.shape == ref.shape for got, ref in checks)
+    n_reads = cfg.reads * (cfg.batches + 1)
+    served = sum(t.result is not None for t in tickets)
+    ok = (finite and shapes and served == n_reads
+          and max_abs <= TOL * max(1.0, scale))
+    res = PhaseResult(name, max_abs, max_abs / max(scale, 1e-30), ok,
+                      compiles_after, served, state_bytes, bytes_in_use)
+    log(f"[{name}] max_abs_err={res.max_abs!r} max_rel_err={res.max_rel!r} "
+        f"tol={TOL}*max(1,{scale!r}) reads_served={served}/{n_reads} "
+        f"pinned_version={tickets[-1].version} "
+        f"compiles_after_warmup={compiles_after} ok={ok}")
+    return res
 
 
-def one_chip_phases(wl: Workload, counter=None, log=print) -> List[PhaseResult]:
+def one_chip_phases(wl: Workload, log=print) -> List[PhaseResult]:
     from repro.dist.sharding import CommsConfig
 
     return [
-        run_phase("a:device/xla-scatter", "device", wl, counter=counter, log=log),
+        run_phase("a:device/xla-scatter", "device", wl, log=log),
         run_phase("b:device/pallas-delta_agg", "device", wl,
-                  comms=CommsConfig(use_pallas_delta=True), counter=counter,
-                  log=log),
+                  comms=CommsConfig(use_pallas_delta=True), log=log),
     ]
 
 
-def four_chip_phases(wl: Workload, shards: int = 4, counter=None,
+def four_chip_phases(wl: Workload, shards: int = 4,
                      log=print) -> List[PhaseResult]:
     return [
         run_phase(f"sharded/{shards}", "sharded", wl, num_shards=shards,
-                  counter=counter, log=log),
+                  log=log),
         run_phase(f"sharded_offload/{shards}", "sharded_offload", wl,
-                  num_shards=shards, counter=counter, log=log),
+                  num_shards=shards, log=log),
     ]
 
 
@@ -290,21 +254,20 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     print(f"device: platform={platform} device_kind={kind!r} "
           f"count={len(devices)}")
     print(f"compile cache: {enable_compile_cache(ROOT)}")
-    counter = CompileCounter()
+    from repro.obs import TALLY
+
     try:
         with jax.default_matmul_precision(MATMUL_PRECISION):
             wl = build_workload(ARXIV)
             if args.chips == 4:
-                results = four_chip_phases(wl, counter=counter)
+                results = four_chip_phases(wl)
             else:
-                results = one_chip_phases(wl, counter=counter)
+                results = one_chip_phases(wl)
     except Exception:
         traceback.print_exc()
         return 1
-    finally:
-        counter.close()
-    print(f"xla executables built={counter.compiles} "
-          f"persistent cache hits={counter.cache_hits}")
+    print(f"xla executables built={TALLY.compiles} "
+          f"persistent cache hits={TALLY.cache_hits}")
     failed = [r.name for r in results if not r.ok]
     # nothing on the path may fall back off the chip: no Pallas interpreter,
     # no kernels/ops.py (which picks the jnp oracle off the TPU)

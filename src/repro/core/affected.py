@@ -26,6 +26,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.full import next_bucket
 from repro.core.operators import GNNModel
 from repro.graph.csr import CSRGraph
@@ -1184,9 +1185,22 @@ def build_packed_plan(
     hwm: Optional[BucketHysteresis] = None,
 ) -> PackedPlan:
     """Alg.-4 planning straight into the packed transfer format."""
-    plan = build_plan(model, g_old, g_new, batch, num_layers)
-    return pack_plan(plan, batch.feat_vertices, batch.feat_values, pallas=pallas,
-                     hwm=hwm)
+    with obs.span("plan/build"):
+        plan = build_plan(model, g_old, g_new, batch, num_layers)
+    with obs.span("plan/pack"):
+        return pack_plan(plan, batch.feat_vertices, batch.feat_values,
+                         pallas=pallas, hwm=hwm)
+
+
+def packed_nbytes(packed: PackedPlan) -> int:
+    """Bytes of the host buffers one batch ships to the device: ``idx``,
+    ``flt``, ``msk``, ``feat_vals`` and the Pallas schedules."""
+    arrays = [packed.idx, packed.flt, packed.msk]
+    if packed.feat_vals is not None:
+        arrays.append(packed.feat_vals)
+    for schedule in packed.pallas or ():
+        arrays.extend(schedule)
+    return sum(int(a.nbytes) for a in arrays)
 
 
 # ====================================================================== #

@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.affected import (
     BatchPlan,
     BucketHysteresis,
@@ -82,6 +83,7 @@ from repro.core.affected import (
     final_write_rows,
     hybrid_plan,
     pack_plan,
+    packed_nbytes,
     remap_compact,
     shard_plan,
     shard_rows,
@@ -134,6 +136,18 @@ class BatchStats:
     #: dispatch time is charged to its first constituent, the others
     #: report ``exec_time_s == 0``).
     fused_window: int = 1
+    #: spans inside the batch (repro.obs), filled by
+    #: ``StreamOrchestrator.apply_batch``: ``pack_time_s`` is the part of
+    #: ``plan_time_s`` spent packing the plan (``repro/plan/pack``),
+    #: ``hook_time_s`` the part of ``exec_time_s`` spent in the ``on_plan``
+    #: hook (``repro/undo_capture``); ``h2d_bytes`` counts the packed plan's
+    #: bytes copied host→device; ``compiles`` / ``compile_time_s`` are the
+    #: executables built inside the batch's graph, plan and exec spans.
+    pack_time_s: float = 0.0
+    hook_time_s: float = 0.0
+    h2d_bytes: int = 0
+    compiles: int = 0
+    compile_time_s: float = 0.0
 
     @property
     def edges_processed(self) -> int:
@@ -164,9 +178,8 @@ class StreamStats:
     Read-side serving fields (ISSUE 6): populated only by
     :class:`repro.serve.frontend.ServingFrontend` — ``reads_served`` /
     ``reads_rejected`` / ``staleness_batches`` are deterministic counters
-    (CI-gated exactly in the smoke bench); ``read_p50_s`` / ``read_p99_s``
-    are submit→serve latency percentiles (telemetry, never gated).  All
-    default to zero so pre-serving baselines and gates keep passing.
+    (CI-gated exactly in the smoke bench).  All default to zero so
+    pre-serving baselines and gates keep passing.
 
     Device hot-row cache counters (ISSUE 8): ``cache_hit_rows`` /
     ``cache_miss_rows`` / ``cache_evictions`` mirror the backend's
@@ -197,8 +210,6 @@ class StreamStats:
     # read-side serving metrics (repro.serve.frontend)
     reads_served: int = 0
     reads_rejected: int = 0
-    read_p50_s: float = 0.0
-    read_p99_s: float = 0.0
     staleness_batches: int = 0
     # device hot-row cache counters (repro.serve.hotcache)
     cache_hit_rows: int = 0
@@ -242,7 +253,6 @@ class StreamStats:
         compute_s                   caller time blocked on the device
         reads_served                frontend reads answered (D)
         reads_rejected              frontend reads shed by admission (D)
-        read_p50_s / read_p99_s     read latency percentiles (telemetry)
         staleness_batches           versions behind head at serve time (D)
         cache_hit_rows              rows served from device cache slots (D)
         cache_miss_rows             rows staged from host (D)
@@ -273,8 +283,6 @@ class StreamStats:
             "compute_s": self.compute_s,
             "reads_served": self.reads_served,
             "reads_rejected": self.reads_rejected,
-            "read_p50_s": self.read_p50_s,
-            "read_p99_s": self.read_p99_s,
             "staleness_batches": self.staleness_batches,
             "cache_hit_rows": self.cache_hit_rows,
             "cache_miss_rows": self.cache_miss_rows,
@@ -731,43 +739,49 @@ class StreamOrchestrator:
     # ------------------------------------------------------------------ #
     def apply_batch(self, batch: UpdateBatch, block: bool = True,
                     on_plan=None) -> BatchStats:
-        t0 = time.perf_counter()
-        g_new = self._apply_graph(batch)
-        t1 = time.perf_counter()
-        mode, prep, decision = self._prepare(g_new, batch)
-        t2 = time.perf_counter()
-        if on_plan is not None and mode != "full":
-            # serving hook (repro.serve.frontend): runs between plan and
-            # dispatch, while the substrate still holds the *pre-batch*
-            # state — the front-end snapshots the plan's write set here to
-            # build its per-version undo log.  Skipped for full-recompute
-            # batches: their pre-images degenerate into a whole-state copy,
-            # so the front-end resets its history instead (BatchStats.mode
-            # tells it to).
-            on_plan(prep)
-        self._dispatch_mode(mode, prep)
-        if block:
-            self.backend.flush()
-            jax.block_until_ready(self.backend.sync_arrays())
-        t3 = time.perf_counter()
+        with obs.span("graph") as sg:
+            g_new = self._apply_graph(batch)
+        with obs.span("plan") as sp:
+            mode, prep, decision = self._prepare(g_new, batch)
+        with obs.span("exec") as sx:
+            if on_plan is not None and mode != "full":
+                # serving hook (repro.serve.frontend): runs between plan and
+                # dispatch, while the substrate still holds the *pre-batch*
+                # state — the front-end snapshots the plan's write set here
+                # to build its per-version undo log.  Skipped for
+                # full-recompute batches: their pre-images degenerate into a
+                # whole-state copy, so the front-end resets its history
+                # instead (BatchStats.mode tells it to).
+                with obs.span("undo_capture"):
+                    on_plan(prep)
+            self._dispatch_mode(mode, prep)
+            if block:
+                with obs.span("sync"):
+                    self.backend.flush()
+                    jax.block_until_ready(self.backend.sync_arrays())
         self.graph = g_new
         if decision is not None:
             # online cost-weight calibration (ISSUE 9): a no-op unless the
             # policy was built with calibrate=True.  block=False feeds the
             # dispatch-only time (the overlap pipeline cannot observe
             # per-batch completion without breaking itself).
-            self.policy.observe(decision, t3 - t2)
+            self.policy.observe(decision, sx.seconds)
         self._after_batch()
         return BatchStats(
             inc_edges=prep.n_inc_edges,
             full_edges=prep.n_full_edges,
             out_vertices=prep.n_out_rows,
-            plan_time_s=t2 - t1,
-            exec_time_s=t3 - t2,
-            graph_time_s=t1 - t0,
+            plan_time_s=sp.seconds,
+            exec_time_s=sx.seconds,
+            graph_time_s=sg.seconds,
             mode=mode,
             est_edges=decision.est_edges if decision is not None else 0,
             est_cost=decision.costs[mode] if decision is not None else 0.0,
+            pack_time_s=sp.inner("plan/pack"),
+            hook_time_s=sx.inner("undo_capture"),
+            h2d_bytes=sx.counts.get("h2d_bytes", 0),
+            compiles=sg.compiles + sp.compiles + sx.compiles,
+            compile_time_s=sg.compile_s + sp.compile_s + sx.compile_s,
         )
 
     # ------------------------------------------------------------------ #
@@ -1326,9 +1340,10 @@ class DeviceBackend(StateBackend):
              base_plan: Optional[BatchPlan] = None):
         if self.fused:
             if base_plan is not None:  # policy path: Alg. 4 already ran
-                return pack_plan(base_plan, batch.feat_vertices,
-                                 batch.feat_values,
-                                 pallas=self.use_pallas_delta, hwm=self.hwm)
+                with obs.span("plan/pack"):
+                    return pack_plan(base_plan, batch.feat_vertices,
+                                     batch.feat_values,
+                                     pallas=self.use_pallas_delta, hwm=self.hwm)
             return build_packed_plan(
                 self.model, g_old, g_new, batch, self.L,
                 pallas=self.use_pallas_delta, hwm=self.hwm,
@@ -1349,14 +1364,17 @@ class DeviceBackend(StateBackend):
         if not self.store_h and self._h[1] is None:
             h = self.reconstruct_h()
             self._h = [self._h[0]] + [with_scratch(v) for v in h[1:]]
-        idx, flt, msk, feat_vals, pallas = jax.device_put(
-            (packed.idx, packed.flt, packed.msk, packed.feat_vals, packed.pallas)
-        )
-        hs, as_, ncts = fused_stream_step(
-            self.model, packed.layout, tuple(self.params),
-            tuple(self._h), tuple(self._a), tuple(self._nct),
-            idx, flt, msk, feat_vals, pallas,
-        )
+        with obs.span("device_put"):
+            obs.count("h2d_bytes", packed_nbytes(packed))
+            idx, flt, msk, feat_vals, pallas = jax.device_put(
+                (packed.idx, packed.flt, packed.msk, packed.feat_vals,
+                 packed.pallas))
+        with obs.span("step"):
+            hs, as_, ncts = fused_stream_step(
+                self.model, packed.layout, tuple(self.params),
+                tuple(self._h), tuple(self._a), tuple(self._nct),
+                idx, flt, msk, feat_vals, pallas,
+            )
         self._h = list(hs)
         self._a = list(as_)
         self._nct = list(ncts)

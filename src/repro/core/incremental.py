@@ -134,79 +134,92 @@ def _layer_body(
     r_cap = touch_rows.shape[0]
     f_cap = f_rows.shape[0]
 
+    # each step runs under its jax.named_scope (HLO metadata only), so a
+    # trace reduction can name a device op's layer and stage
+
     # ---------------- step 1: signed delta messages (Alg.1 l.1-3) -------
-    if pallas_delta is not None:
-        # put the records in the kernel's block-CSR order before computing
-        # their messages: permuting the narrow record fields is cheap, while
-        # permuting the [E, d] messages would hold a second message-sized
-        # buffer in device memory.  Block pads are masked and row-less.
-        perm = pallas_delta[0]
-        live = perm >= 0
-        safe = jnp.where(live, perm, 0)
-        e_src, e_dst, e_sign, e_use_new, e_w, e_t = (
-            f[safe] for f in (e_src, e_dst, e_sign, e_use_new, e_w, e_t))
-        e_rowidx = jnp.where(live, e_rowidx[safe], r_cap)
-        e_mask = e_mask[safe] & live
-    # one gather per endpoint from the stacked [old | new] table: selecting
-    # between two gathers would hold two [E, d] buffers in device memory
-    h_both = jnp.concatenate([h_prev_old, h_prev_new], axis=0)
-    view = jnp.where(e_use_new, h_prev_old.shape[0], 0)
-    h_u = h_both[e_src + view]
-    if model.dest_dependent:
-        h_v = h_both[e_dst + view]
-    else:
-        # Theorem 1 requires ms_local independent of the destination for
-        # unconstrained models — skip the h[dst] halo gather entirely
-        # (≈2× less collective traffic at pod scale; EXPERIMENTS.md §Perf)
-        h_v = jnp.zeros((e_src.shape[0], h_prev_new.shape[1]), h_prev_new.dtype)
-    s_u = jnp.where(e_use_new, deg_new[e_src], deg_old[e_src])
-    s_v = jnp.where(e_use_new, deg_new[e_dst], deg_old[e_dst])
-    ctx, raw = edge_messages(model, p, h_u, h_v, s_u, s_v, e_w, e_t)
-    scale = (e_sign * e_mask.astype(raw.dtype))[:, None]
-    ctx = ctx * scale
-    raw = raw * scale
+    with jax.named_scope("messages"):
+        if pallas_delta is not None:
+            # put the records in the kernel's block-CSR order before
+            # computing their messages: permuting the narrow record fields
+            # is cheap, while permuting the [E, d] messages would hold a
+            # second message-sized buffer in device memory.  Block pads are
+            # masked and row-less.
+            perm = pallas_delta[0]
+            live = perm >= 0
+            safe = jnp.where(live, perm, 0)
+            e_src, e_dst, e_sign, e_use_new, e_w, e_t = (
+                f[safe] for f in (e_src, e_dst, e_sign, e_use_new, e_w, e_t))
+            e_rowidx = jnp.where(live, e_rowidx[safe], r_cap)
+            e_mask = e_mask[safe] & live
+        # one gather per endpoint from the stacked [old | new] table:
+        # selecting between two gathers would hold two [E, d] buffers in
+        # device memory
+        h_both = jnp.concatenate([h_prev_old, h_prev_new], axis=0)
+        view = jnp.where(e_use_new, h_prev_old.shape[0], 0)
+        h_u = h_both[e_src + view]
+        if model.dest_dependent:
+            h_v = h_both[e_dst + view]
+        else:
+            # Theorem 1 requires ms_local independent of the destination for
+            # unconstrained models — skip the h[dst] halo gather entirely
+            # (≈2× less collective traffic at pod scale; EXPERIMENTS.md §Perf)
+            h_v = jnp.zeros((e_src.shape[0], h_prev_new.shape[1]),
+                            h_prev_new.dtype)
+        s_u = jnp.where(e_use_new, deg_new[e_src], deg_old[e_src])
+        s_v = jnp.where(e_use_new, deg_new[e_dst], deg_old[e_dst])
+        ctx, raw = edge_messages(model, p, h_u, h_v, s_u, s_v, e_w, e_t)
+        scale = (e_sign * e_mask.astype(raw.dtype))[:, None]
+        ctx = ctx * scale
+        raw = raw * scale
 
     # compact scatter into touched-row space (O(affected), not O(V))
     # (the narrow context column always takes XLA's segment-sum)
-    d_nct = jax.ops.segment_sum(ctx, e_rowidx, num_segments=r_cap + 1)[:r_cap]
-    if pallas_delta is not None:
-        d_s = _pallas_delta_scatter(raw, pallas_delta, r_cap)
-    else:
-        d_s = jax.ops.segment_sum(raw, e_rowidx, num_segments=r_cap + 1)[:r_cap]
+    with jax.named_scope("scatter"):
+        d_nct = jax.ops.segment_sum(ctx, e_rowidx,
+                                    num_segments=r_cap + 1)[:r_cap]
+        if pallas_delta is not None:
+            d_s = _pallas_delta_scatter(raw, pallas_delta, r_cap)
+        else:
+            d_s = jax.ops.segment_sum(raw, e_rowidx,
+                                      num_segments=r_cap + 1)[:r_cap]
 
     # ---------------- step 2: cbn⁻¹ → delta-agg → cbn (Alg.1 l.4-6) -----
-    nct_old_rows = nct_ext[touch_rows]
-    a_rows = a_ext[touch_rows]
-    nct_new_rows = nct_old_rows + d_nct
-    s_rows = model.ms_cbn_inv(p, nct_old_rows, a_rows) + d_s
-    a_new_rows = model.ms_cbn(p, nct_new_rows, s_rows)
-    # padded rows in touch_rows all point at the scratch slot n
-    a_ext = a_ext.at[touch_rows].set(a_new_rows)
-    nct_ext = nct_ext.at[touch_rows].set(nct_new_rows)
+    with jax.named_scope("delta_agg"):
+        nct_old_rows = nct_ext[touch_rows]
+        a_rows = a_ext[touch_rows]
+        nct_new_rows = nct_old_rows + d_nct
+        s_rows = model.ms_cbn_inv(p, nct_old_rows, a_rows) + d_s
+        a_new_rows = model.ms_cbn(p, nct_new_rows, s_rows)
+        # padded rows in touch_rows all point at the scratch slot n
+        a_ext = a_ext.at[touch_rows].set(a_new_rows)
+        nct_ext = nct_ext.at[touch_rows].set(nct_new_rows)
 
     # ---------------- step 3: constrained full recompute (§IV-C) --------
     if f_rows.shape[0] > 0:
-        fa, fnct, _ = subset_layer(
-            model,
-            p,
-            h_prev_new,
-            f_rows_h,
-            f_mask,
-            f_src,
-            f_rowidx,
-            f_w,
-            f_t,
-            f_emask,
-            deg_new,
-            f_cap,
-        )
-        a_ext = a_ext.at[f_rows].set(fa)
-        nct_ext = nct_ext.at[f_rows].set(fnct)
+        with jax.named_scope("constrained"):
+            fa, fnct, _ = subset_layer(
+                model,
+                p,
+                h_prev_new,
+                f_rows_h,
+                f_mask,
+                f_src,
+                f_rowidx,
+                f_w,
+                f_t,
+                f_emask,
+                deg_new,
+                f_cap,
+            )
+            a_ext = a_ext.at[f_rows].set(fa)
+            nct_ext = nct_ext.at[f_rows].set(fnct)
 
     # ---------------- step 4: vertex-wise update (Alg.1 l.7) ------------
-    h_prev_rows = h_prev_new[out_rows_h]
-    h_rows = model.update(p, h_prev_rows, a_ext[out_rows])
-    h_ext = h_ext.at[out_rows].set(h_rows)
+    with jax.named_scope("update"):
+        h_prev_rows = h_prev_new[out_rows_h]
+        h_rows = model.update(p, h_prev_rows, a_ext[out_rows])
+        h_ext = h_ext.at[out_rows].set(h_rows)
     return a_ext, nct_ext, h_ext
 
 
@@ -301,22 +314,25 @@ def fused_stream_step(
         gi = {name: idx[s] for name, s in idx_sl[l].items()}
         gf = {name: flt[s] for name, s in flt_sl[l].items()}
         gm = {name: msk[s] for name, s in msk_sl[l].items()}
-        an, nn, hn = _layer_body(
-            model, params[l], h_prev_old, h_prev_new, deg_old, deg_new,
-            a_exts[l], nct_exts[l], h_exts[l + 1],
-            gi["e_src"], gi["e_dst"], gi["e_rowidx"], gf["e_sign"],
-            gm["e_use_new"], gf["e_w"], gi["e_t"], gm["e_mask"],
-            gi["touch_rows"], gm["touch_mask"],
-            gi["f_rows"], gm["f_mask"], gi["f_src"], gi["f_rowidx"],
-            gf["f_w"], gi["f_t"], gm["f_emask"],
-            gi["out_rows"], gm["out_mask"],
-            pallas_delta=None if pallas is None else pallas[l],
-        )
-        # re-zero the scratch row: padded scatters may have written NaN-prone
-        # values (e.g. ms_cbn_inv(0, 0)) and the state persists across batches
-        an = an.at[n].set(0.0)
-        nn = nn.at[n].set(0.0)
-        hn = hn.at[n].set(0.0)
+        with jax.named_scope(f"layer{l}"):
+            an, nn, hn = _layer_body(
+                model, params[l], h_prev_old, h_prev_new, deg_old, deg_new,
+                a_exts[l], nct_exts[l], h_exts[l + 1],
+                gi["e_src"], gi["e_dst"], gi["e_rowidx"], gf["e_sign"],
+                gm["e_use_new"], gf["e_w"], gi["e_t"], gm["e_mask"],
+                gi["touch_rows"], gm["touch_mask"],
+                gi["f_rows"], gm["f_mask"], gi["f_src"], gi["f_rowidx"],
+                gf["f_w"], gi["f_t"], gm["f_emask"],
+                gi["out_rows"], gm["out_mask"],
+                pallas_delta=None if pallas is None else pallas[l],
+            )
+            # re-zero the scratch row: padded scatters may have written
+            # NaN-prone values (e.g. ms_cbn_inv(0, 0)) and the state
+            # persists across batches
+            with jax.named_scope("update"):
+                an = an.at[n].set(0.0)
+                nn = nn.at[n].set(0.0)
+                hn = hn.at[n].set(0.0)
         as_.append(an)
         ncts.append(nn)
         hs.append(hn)
@@ -402,59 +418,61 @@ def sharded_step_fn(model: GNNModel, mesh, axis: str):
             hs = [h0_new]
             as_, ncts = [], []
             for l in range(len(slayout.caps)):
-                # ---- halo exchange: frontier source rows only ----
-                d_prev = h_prev_old.shape[1]
-                halo_cap = slayout.caps[l][5]
-                if use_ppermute and S > 1:
-                    # per-consumer rotation rounds: round k moves pair
-                    # (owner j → consumer (j+k) mod S); send pads gather
-                    # the scratch row, recv pads land in the dump row
-                    # (index halo_cap, sliced off).  Positions no consumer
-                    # receives stay zero — this shard never gathers them.
-                    send_pos, recv_pos = comms[l]
-                    buf = jnp.zeros((halo_cap + 1, 2 * d_prev),
-                                    h_prev_old.dtype)
-                    for k in range(1, S):
-                        perm = rotation_perm(S, k)
-                        sp_ = send_pos[k - 1]
-                        cat = jnp.concatenate(
-                            [h_prev_old[sp_], h_prev_new[sp_]], axis=1)
-                        rec = lax.ppermute(cat, axis, perm)
-                        buf = buf.at[recv_pos[k - 1]].set(rec)
-                    halo = buf[:halo_cap]
-                else:
-                    halo_rows = idx_r[halo_sl[l]]  # global ids, pad → -1
-                    own = (halo_rows >= lo) & (halo_rows < lo + rows_per)
-                    pos = jnp.where(own, halo_rows - lo, rows_per)
-                    cat = jnp.concatenate(
-                        [h_prev_old[pos], h_prev_new[pos]], axis=1)
-                    halo = lax.psum(jnp.where(own[:, None], cat, 0.0), axis)
-                ws_old = jnp.concatenate([halo[:, :d_prev], h_prev_old], axis=0)
-                ws_new = jnp.concatenate([halo[:, d_prev:], h_prev_new], axis=0)
+                with jax.named_scope(f"layer{l}"):
+                    # ---- halo exchange: frontier source rows only ----
+                    with jax.named_scope("halo"):
+                        d_prev = h_prev_old.shape[1]
+                        halo_cap = slayout.caps[l][5]
+                        if use_ppermute and S > 1:
+                            # per-consumer rotation rounds: round k moves pair
+                            # (owner j → consumer (j+k) mod S); send pads gather
+                            # the scratch row, recv pads land in the dump row
+                            # (index halo_cap, sliced off).  Positions no consumer
+                            # receives stay zero — this shard never gathers them.
+                            send_pos, recv_pos = comms[l]
+                            buf = jnp.zeros((halo_cap + 1, 2 * d_prev),
+                                            h_prev_old.dtype)
+                            for k in range(1, S):
+                                perm = rotation_perm(S, k)
+                                sp_ = send_pos[k - 1]
+                                cat = jnp.concatenate(
+                                    [h_prev_old[sp_], h_prev_new[sp_]], axis=1)
+                                rec = lax.ppermute(cat, axis, perm)
+                                buf = buf.at[recv_pos[k - 1]].set(rec)
+                            halo = buf[:halo_cap]
+                        else:
+                            halo_rows = idx_r[halo_sl[l]]  # global ids, pad → -1
+                            own = (halo_rows >= lo) & (halo_rows < lo + rows_per)
+                            pos = jnp.where(own, halo_rows - lo, rows_per)
+                            cat = jnp.concatenate(
+                                [h_prev_old[pos], h_prev_new[pos]], axis=1)
+                            halo = lax.psum(jnp.where(own[:, None], cat, 0.0), axis)
+                        ws_old = jnp.concatenate([halo[:, :d_prev], h_prev_old], axis=0)
+                        ws_new = jnp.concatenate([halo[:, d_prev:], h_prev_new], axis=0)
 
-                gi = {k: idx_s[s] for k, s in idx_sl[l].items()}
-                gf = {k: flt_s[s] for k, s in flt_sl[l].items()}
-                gm = {k: msk_s[s] for k, s in msk_sl[l].items()}
-                an, nn, hn = _layer_body(
-                    model, prm[l], ws_old, ws_new, gf["deg_old"], gf["deg_new"],
-                    a_bl[l], nct_bl[l], h_bl[l + 1],
-                    gi["e_src"], gi["e_dst"], gi["e_rowidx"], gf["e_sign"],
-                    gm["e_use_new"], gf["e_w"], gi["e_t"], gm["e_mask"],
-                    gi["touch_rows"], gm["touch_mask"],
-                    gi["f_rows"], gm["f_mask"], gi["f_src"], gi["f_rowidx"],
-                    gf["f_w"], gi["f_t"], gm["f_emask"],
-                    gi["out_rows"], gm["out_mask"],
-                    f_rows_h=gi["f_rows_h"], out_rows_h=gi["out_rows_h"],
-                    pallas_delta=pal[l] if use_pallas else None,
-                )
-                an = an.at[rows_per].set(0.0)  # re-zero local scratch row
-                nn = nn.at[rows_per].set(0.0)
-                hn = hn.at[rows_per].set(0.0)
-                as_.append(an)
-                ncts.append(nn)
-                hs.append(hn)
-                h_prev_old = h_bl[l + 1]
-                h_prev_new = hn
+                    gi = {k: idx_s[s] for k, s in idx_sl[l].items()}
+                    gf = {k: flt_s[s] for k, s in flt_sl[l].items()}
+                    gm = {k: msk_s[s] for k, s in msk_sl[l].items()}
+                    an, nn, hn = _layer_body(
+                        model, prm[l], ws_old, ws_new, gf["deg_old"], gf["deg_new"],
+                        a_bl[l], nct_bl[l], h_bl[l + 1],
+                        gi["e_src"], gi["e_dst"], gi["e_rowidx"], gf["e_sign"],
+                        gm["e_use_new"], gf["e_w"], gi["e_t"], gm["e_mask"],
+                        gi["touch_rows"], gm["touch_mask"],
+                        gi["f_rows"], gm["f_mask"], gi["f_src"], gi["f_rowidx"],
+                        gf["f_w"], gi["f_t"], gm["f_emask"],
+                        gi["out_rows"], gm["out_mask"],
+                        f_rows_h=gi["f_rows_h"], out_rows_h=gi["out_rows_h"],
+                        pallas_delta=pal[l] if use_pallas else None,
+                    )
+                    an = an.at[rows_per].set(0.0)  # re-zero local scratch row
+                    nn = nn.at[rows_per].set(0.0)
+                    hn = hn.at[rows_per].set(0.0)
+                    as_.append(an)
+                    ncts.append(nn)
+                    hs.append(hn)
+                    h_prev_old = h_bl[l + 1]
+                    h_prev_new = hn
             return (
                 tuple(h[None] for h in hs),
                 tuple(a[None] for a in as_),

@@ -12,8 +12,8 @@ from __future__ import annotations
 
 _API = ("create_engine", "EngineConfig", "BACKENDS", "ChunkedRTECEngine",
         "serving_frontend", "FusionConfig")
-_FRONTEND = ("ServingFrontend", "ReadTicket", "ReadRejectedError",
-             "StaleVersionError")
+_FRONTEND = ("ServingFrontend", "ReadTicket", "ReadRound",
+             "ReadRejectedError", "StaleVersionError")
 _CACHE = ("CacheConfig", "CacheStats", "HotRowCache")
 _STAGING = ("StagingConfig",)
 

@@ -40,9 +40,12 @@ Serving API — the version/consistency contract
   at version boundaries, where the host-resident substrates' worker queues
   are already drained (see ``StateBackend.snapshot_rows``).
 
-Read-side telemetry (``reads_served``, ``reads_rejected``, submit→serve
-latency p50/p99, cumulative staleness in batches) reports through the same
-:class:`StreamStats` every other entry point returns.
+Read-side counters (``reads_served``, ``reads_rejected``, cumulative
+staleness in batches) report through the same :class:`StreamStats` every
+other entry point returns.  Each serving round that answers a read appends
+a :class:`ReadRound` to ``read_rounds``: where its time went (the union
+gathers, the undo walk) and the executables it had to build, read from the
+round's ``repro/serve_reads`` span (:mod:`repro.obs`).
 
 The frontend is deliberately single-threaded and deterministic: reads are
 admitted any time, but service happens at micro-batch points (before each
@@ -57,6 +60,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.backend import (
     BatchStats,
     StreamOrchestrator,
@@ -106,6 +110,22 @@ class ReadTicket:
         return self.result
 
 
+@dataclasses.dataclass(frozen=True)
+class ReadRound:
+    """One :meth:`ServingFrontend.serve_reads` call that answered reads,
+    from its ``repro/serve_reads`` span."""
+
+    start_s: float  # perf_counter when the round began
+    seconds: float  # the whole round
+    reads: int  # reads answered
+    groups: int  # pinned versions, one union gather each
+    union_rows: int  # rows gathered, summed over the groups
+    gather_s: float  # the union gathers (``repro/read_gather``)
+    undo_s: float  # the undo-log walks (``repro/read_undo``)
+    compiles: int  # executables built inside the round
+    compile_s: float
+
+
 @dataclasses.dataclass
 class _UndoRecord:
     """Pre-images of the rows batch ``version`` wrote: applying this record
@@ -114,7 +134,7 @@ class _UndoRecord:
 
     version: int
     rows: np.ndarray  # sorted unique int64
-    vals: np.ndarray  # [len(rows), d] pre-batch values
+    vals: np.ndarray  # [len(rows), d] pre-batch values ([0, 0] for no row)
 
 
 class ServingFrontend:
@@ -149,7 +169,7 @@ class ServingFrontend:
         self._undo: List[_UndoRecord] = []  # ascending by .version
         self._pending: List[ReadTicket] = []
         self._batch_stats: List[BatchStats] = []
-        self._latencies: List[float] = []
+        self.read_rounds: List[ReadRound] = []
         self._wall_s = 0.0
         self._plan_s = 0.0
         self.reads_served = 0
@@ -209,11 +229,13 @@ class ServingFrontend:
     def _reconstruct(self, rows: np.ndarray, pin: int) -> np.ndarray:
         """Rows at version ``pin``: gather current values, then walk the
         undo records C→pin+1 overriding any row they wrote."""
-        vals = np.array(self._orch.backend.snapshot_rows(rows))
-        for rec in reversed(self._undo):
-            if rec.version <= pin:
-                break
-            _override_rows(vals, rows, rec.rows, rec.vals)
+        with obs.span("read_gather"):
+            vals = np.array(self._orch.backend.snapshot_rows(rows))
+        with obs.span("read_undo"):
+            for rec in reversed(self._undo):
+                if rec.version <= pin:
+                    break
+                _override_rows(vals, rows, rec.rows, rec.vals)
         return vals
 
     def serve_reads(self) -> int:
@@ -223,34 +245,50 @@ class ServingFrontend:
         due = [t for t in self._pending if t.version <= self.version]
         if not due:
             return 0
-        served = 0
-        for pin in sorted({t.version for t in due}):
-            group = [t for t in due if t.version == pin]
-            if pin < self._floor:  # floor moved while queued
+        served = groups = union_rows = 0
+        with obs.span("serve_reads") as sr:
+            for pin in sorted({t.version for t in due}):
+                group = [t for t in due if t.version == pin]
+                if pin < self._floor:  # floor moved while queued
+                    for t in group:
+                        self._pending.remove(t)
+                        t.error = StaleVersionError(
+                            f"read pinned at version {pin} fell below the "
+                            f"undo history floor {self._floor} while queued")
+                        self.reads_rejected += 1
+                    continue
+                # one gather for the union of the group's rows, scattered back
+                union = np.unique(np.concatenate([t.rows for t in group]))
+                union_vals = self._reconstruct(union, pin)
+                groups += 1
+                union_rows += union.shape[0]
                 for t in group:
                     self._pending.remove(t)
-                    t.error = StaleVersionError(
-                        f"read pinned at version {pin} fell below the undo "
-                        f"history floor {self._floor} while queued")
-                    self.reads_rejected += 1
-                continue
-            # one gather for the union of the group's rows, scattered back
-            union = np.unique(np.concatenate([t.rows for t in group]))
-            union_vals = self._reconstruct(union, pin)
-            now = time.perf_counter()
-            for t in group:
-                self._pending.remove(t)
-                t.result = union_vals[np.searchsorted(union, t.rows)]
-                t.served_version = self.version
-                self._latencies.append(now - t.submitted_s)
-                self.staleness_batches += t.staleness
-                served += 1
+                    t.result = union_vals[np.searchsorted(union, t.rows)]
+                    t.served_version = self.version
+                    self.staleness_batches += t.staleness
+                    served += 1
+        if served:
+            self.read_rounds.append(ReadRound(
+                start_s=sr.start, seconds=sr.seconds, reads=served,
+                groups=groups, union_rows=union_rows,
+                gather_s=sr.inner("read_gather"), undo_s=sr.inner("read_undo"),
+                compiles=sr.compiles, compile_s=sr.compile_s))
         self.reads_served += served
         return served
 
     # ------------------------------------------------------------------ #
     # write path
     # ------------------------------------------------------------------ #
+    def _capture(self, version: int, prep) -> _UndoRecord:
+        """Pre-images of ``prep``'s write set (``write_set`` resolves the
+        plan's final-layer rows whatever execution mode the policy chose);
+        an empty write set needs no gather."""
+        rows = np.asarray(self._orch.write_set(prep), np.int64)
+        vals = (np.array(self._orch.backend.snapshot_rows(rows)) if rows.size
+                else np.empty((0, 0), np.float32))
+        return _UndoRecord(version=version, rows=rows, vals=vals)
+
     def apply_batch(self, batch: UpdateBatch) -> BatchStats:
         """Serve due reads, then apply one update batch as a full version
         boundary (the undo pre-images are captured between the batch's plan
@@ -260,14 +298,10 @@ class ServingFrontend:
         captured: List[_UndoRecord] = []
 
         def on_plan(prep) -> None:
-            # write_set resolves the plan's final-layer rows whatever
-            # execution mode the orchestrator's policy chose; the hook is
-            # never invoked for full-recompute batches (their pre-images
-            # would be a whole-state copy) — those reset the history below
-            rows = np.asarray(self._orch.write_set(prep), np.int64)
-            captured.append(_UndoRecord(
-                version=self.version + 1, rows=rows,
-                vals=np.array(self._orch.backend.snapshot_rows(rows))))
+            # the hook is never invoked for full-recompute batches (their
+            # pre-images would be a whole-state copy) — those reset the
+            # history below
+            captured.append(self._capture(self.version + 1, prep))
 
         bs = self._orch.apply_batch(batch, block=True, on_plan=on_plan)
         self.version += 1
@@ -314,10 +348,8 @@ class ServingFrontend:
         def on_plan(plan) -> None:
             # called once per constituent, before dispatch: version numbers
             # are assigned in stream order on top of the current version
-            rows = np.asarray(self._orch.write_set(plan), np.int64)
-            captured.append(_UndoRecord(
-                version=self.version + 1 + len(captured), rows=rows,
-                vals=np.array(self._orch.backend.snapshot_rows(rows))))
+            captured.append(self._capture(self.version + 1 + len(captured),
+                                          plan))
 
         out = self._orch.apply_window(batches, on_plan=on_plan)
         orch = self._orch
@@ -373,7 +405,6 @@ class ServingFrontend:
     # ------------------------------------------------------------------ #
     def stats(self) -> StreamStats:
         """The run so far as the repo's single result type."""
-        lat = np.asarray(self._latencies, np.float64)
         orch = self._orch
         return StreamStats(
             batches=list(self._batch_stats),
@@ -381,8 +412,6 @@ class ServingFrontend:
             plan_s=self._plan_s,
             reads_served=self.reads_served,
             reads_rejected=self.reads_rejected,
-            read_p50_s=float(np.percentile(lat, 50)) if lat.size else 0.0,
-            read_p99_s=float(np.percentile(lat, 99)) if lat.size else 0.0,
             staleness_batches=self.staleness_batches,
             fusion_windows=orch.fusion_windows - self._fusion0[0],
             fused_batches=orch.fused_batches - self._fusion0[1],

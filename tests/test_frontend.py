@@ -88,7 +88,12 @@ def test_versioned_reads_bitwise_offload_backends(backend, async_staging):
     # after batch i (version i+1) we read versions 0..i+1 → i+2 reads
     assert ss.reads_served == sum(i + 2 for i in range(len(wl.batches)))
     assert ss.reads_rejected == 0
-    assert ss.read_p99_s >= ss.read_p50_s > 0.0
+    # each read() is its own serving round: one record per round, one
+    # pinned group each, its gather and undo walk inside the round's time
+    assert [r.reads for r in fr.read_rounds] == [1] * ss.reads_served
+    assert all(r.groups == 1 and r.union_rows == rows.size
+               and 0.0 < r.gather_s + r.undo_s <= r.seconds
+               for r in fr.read_rounds)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -313,14 +318,19 @@ def test_stream_stats_as_dict_defaults_and_read_fields():
     # read-side fields default to zero so pre-serving baselines keep passing
     for k in ("reads_served", "reads_rejected", "staleness_batches"):
         assert d[k] == 0
-    for k in ("read_p50_s", "read_p99_s"):
-        assert d[k] == 0.0
     model = make_model("gcn")
     x, wl = _mk_stream(num_batches=2)
     fr = ServingFrontend(create_engine("offload", _cfg(model, wl, x)))
     ss = fr.run_stream(wl.batches)
+    # rounds that served nothing leave no per-round record
+    assert fr.read_rounds == []
     assert isinstance(ss, StreamStats) and len(ss.batches) == 2
     d = ss.as_dict()
     assert d["n_batches"] == 2 and d["wall_s"] == ss.wall_s
     assert set(d) >= {"staged_bytes", "prefetch_hits", "reads_served",
-                      "read_p99_s", "staleness_batches"}
+                      "staleness_batches"}
+    fr.submit_read([0, 1, 1])
+    fr.submit_read([1, 2], version=fr.version - 1)
+    assert fr.drain() == 2
+    (r,) = fr.read_rounds
+    assert (r.reads, r.groups, r.union_rows) == (2, 2, 4)
