@@ -105,17 +105,33 @@ def final_write_rows(plan: BatchPlan) -> np.ndarray:
     return np.unique(lp.out_rows[lp.out_mask].astype(np.int64))
 
 
+def _segments(indptr: np.ndarray, rows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """CSR positions of the adjacency segments of ``rows``, concatenated in
+    the order of ``rows`` (CSR order within each), and for each position
+    the index into ``rows`` of the segment it belongs to."""
+    lo = indptr[rows]
+    cnt = indptr[rows + 1] - lo
+    seg = np.repeat(np.arange(rows.shape[0]), cnt)
+    pos = np.arange(seg.shape[0]) + np.repeat(lo - (np.cumsum(cnt) - cnt), cnt)
+    return pos, seg
+
+
+def _vertex_mask(n: int, vertices) -> np.ndarray:
+    mask = np.zeros(n, bool)
+    mask[np.fromiter(vertices, np.int64, len(vertices))] = True
+    return mask
+
+
 def _lookup_in_edge_data(g: CSRGraph, src: np.ndarray, dst: np.ndarray):
-    """Vectorized (weight, etype) lookup for existing edges (u, v)."""
-    w = np.empty(src.shape[0], np.float32)
-    t = np.empty(src.shape[0], np.int32)
-    for i, (u, v) in enumerate(zip(src, dst)):
-        nbrs, ws, ts = g.in_edge_data(int(v))
-        j = np.searchsorted(nbrs, u)
-        assert j < nbrs.shape[0] and nbrs[j] == u, f"edge ({u},{v}) missing"
-        w[i] = ws[j]
-        t[i] = ts[j]
-    return w, t
+    """Vectorized (weight, etype) lookup for existing edges (u, v); raises
+    ValueError if one is missing."""
+    pos, seg = _segments(g.in_indptr, dst)
+    hit = g.in_indices[pos] == src[seg]  # at most one per edge: no duplicates
+    if int(hit.sum()) != src.shape[0]:
+        i = np.setdiff1d(np.arange(src.shape[0]), seg[hit])[0]
+        raise ValueError(f"edge ({src[i]},{dst[i]}) missing")
+    pos = pos[hit]
+    return g.in_weights[pos], g.in_etypes[pos]
 
 
 def _pad_records(
@@ -129,7 +145,10 @@ def _pad_records(
 ) -> Tuple[np.ndarray, ...]:
     e = src.shape[0]
     e_cap = next_bucket(e)
-    rows, rowinv = np.unique(dst, return_inverse=True) if e else (np.zeros(0, np.int64), np.zeros(0, np.int64))
+    # rows = unique(dst) and rowinv its inverse, by a count over n (no sort)
+    present = np.bincount(dst, minlength=n) > 0
+    rows = np.nonzero(present)[0]
+    rowinv = (np.cumsum(present) - 1)[dst]
     r_cap = next_bucket(rows.shape[0])
 
     def pad(a, cap, fill, dt):
@@ -163,11 +182,17 @@ def build_plan(
 
     ``restrict`` (ODEC, paper §V-D): optional per-layer vertex sets; layer
     l's work is intersected with ``restrict[l]`` (the query-induced K-hop
-    cone), turning RTEC into on-demand embedding computation."""
+    cone), turning RTEC into on-demand embedding computation.
+
+    Every step is an array operation over the CSR arrays and vertex masks
+    of length n; records come out in a fixed order: inserts, deletes, then
+    one (−, old) / (+, new) pair per out-edge of each changed source, by
+    ascending source and CSR order within it."""
     n = g_old.n
     deg_old = g_old.in_degree().astype(np.float32)
     deg_new = g_new.in_degree().astype(np.float32)
-    deg_changed = np.nonzero(deg_old != deg_new)[0]
+    deg_changed = deg_old != deg_new
+    has_in = deg_new > 0
 
     ins_s = np.asarray(batch.ins_src, np.int64)
     ins_d = np.asarray(batch.ins_dst, np.int64)
@@ -183,95 +208,71 @@ def build_plan(
     )
     del_s = np.asarray(batch.del_src, np.int64)
     del_d = np.asarray(batch.del_dst, np.int64)
-    if del_s.size:
-        del_w, del_t = _lookup_in_edge_data(g_old, del_s, del_d)
-    else:
-        del_w = np.zeros(0, np.float32)
-        del_t = np.zeros(0, np.int32)
-    inserted_keys = set(zip(ins_s.tolist(), ins_d.tolist()))
+    del_w, del_t = _lookup_in_edge_data(g_old, del_s, del_d)
+    inserted_keys = np.unique(ins_s * n + ins_d)
+    ins_src = np.zeros(n, bool)
+    ins_src[ins_s] = True
 
     changed0 = (
         np.asarray(batch.feat_vertices, np.int64)
         if batch.feat_vertices is not None
         else np.zeros(0, np.int64)
     )
-    changed_h = changed0  # vertices whose h^{l-1} changed
-    deg_new_int = g_new.in_degree()
+    changed = np.zeros(n, bool)  # vertices whose h^{l-1} changed
+    changed[changed0] = True
 
     plans: List[LayerPlan] = []
     for layer_idx in range(num_layers):
-        allowed = restrict[layer_idx] if restrict is not None else None
-        changed_set = set(changed_h.tolist())
+        allowed_set = restrict[layer_idx] if restrict is not None else None
+        allowed = _vertex_mask(n, allowed_set) if allowed_set is not None else None
         # sources whose outgoing contributions changed
-        c_src = set(changed_set)
-        if model.src_struct_dependent:
-            c_src |= set(deg_changed.tolist())
+        c_src = np.nonzero(changed | deg_changed if model.src_struct_dependent else changed)[0]
         # constrained full-recompute destinations
+        v_full = np.zeros(0, np.int64)
+        dest_ok = allowed  # destinations that take incremental records
         if model.dest_dependent:
-            v_full = np.array(
-                sorted(
-                    v
-                    for v in changed_set
-                    if deg_new_int[v] > 0 and (allowed is None or v in allowed)
-                ),
-                np.int64,
-            )
-        else:
-            v_full = np.zeros(0, np.int64)
-        v_full_set = set(v_full.tolist())
+            full = changed & has_in if allowed is None else changed & has_in & allowed
+            v_full = np.nonzero(full)[0]
+            if v_full.size:
+                dest_ok = ~full if allowed is None else allowed & ~full
+
+        def kept(dst: np.ndarray) -> np.ndarray:
+            return np.ones(dst.shape[0], bool) if dest_ok is None else dest_ok[dst]
 
         # ---- incremental records ----
-        rs, rd, rsign, rnew, rw, rt = [], [], [], [], [], []
-        n_changed_edges = 0
+        pos, seg = _segments(g_new.out_indptr, c_src)
+        u = c_src[seg]
+        d = g_new.out_indices[pos]
+        keep = kept(d)
+        if inserted_keys.size:
+            cand = np.nonzero(ins_src[u])[0]
+            key = u[cand] * n + d[cand]
+            j = np.minimum(np.searchsorted(inserted_keys, key), inserted_keys.size - 1)
+            keep[cand[inserted_keys[j] == key]] = False
+        pos, u, d = pos[keep], u[keep], d[keep]
+        n_changed_edges = u.shape[0]
+        ki, kd = kept(ins_d), kept(del_d)
+        n_ins, n_del = int(ki.sum()), int(kd.sum())
 
-        def _emit(s, d, sign, usenew, w, t):
-            rs.append(s)
-            rd.append(d)
-            rsign.append(sign)
-            rnew.append(usenew)
-            rw.append(w)
-            rt.append(t)
-
-        def _allowed(d: int) -> bool:
-            return allowed is None or d in allowed
-
-        for i in range(ins_s.shape[0]):
-            if int(ins_d[i]) not in v_full_set and _allowed(int(ins_d[i])):
-                _emit(ins_s[i], ins_d[i], 1.0, True, ins_w[i], ins_t[i])
-        for i in range(del_s.shape[0]):
-            if int(del_d[i]) not in v_full_set and _allowed(int(del_d[i])):
-                _emit(del_s[i], del_d[i], -1.0, False, del_w[i], del_t[i])
-        for u in sorted(c_src):
-            nbrs, ws, ts = g_new.out_edge_data(int(u))
-            for j in range(nbrs.shape[0]):
-                d = int(nbrs[j])
-                if (int(u), d) in inserted_keys or d in v_full_set or not _allowed(d):
-                    continue
-                _emit(u, d, -1.0, False, ws[j], ts[j])
-                _emit(u, d, 1.0, True, ws[j], ts[j])
-                n_changed_edges += 1
-
+        src = np.concatenate([ins_s[ki], del_s[kd], np.repeat(u, 2)])
         rec = _pad_records(
             n,
-            np.array(rs, np.int64),
-            np.array(rd, np.int64),
-            np.array(rsign, np.float32),
-            np.array(rnew, bool),
-            np.array(rw, np.float32),
-            np.array(rt, np.int32),
+            src,
+            np.concatenate([ins_d[ki], del_d[kd], np.repeat(d, 2)]),
+            np.concatenate([np.ones(n_ins, np.float32), np.full(n_del, -1.0, np.float32),
+                            np.tile(np.array([-1.0, 1.0], np.float32), n_changed_edges)]),
+            np.concatenate([np.ones(n_ins, bool), np.zeros(n_del, bool),
+                            np.tile(np.array([False, True]), n_changed_edges)]),
+            np.concatenate([ins_w[ki], del_w[kd], np.repeat(g_new.out_weights[pos], 2)]),
+            np.concatenate([ins_t[ki], del_t[kd], np.repeat(g_new.out_etypes[pos], 2)]),
         )
         (e_src, e_dst, e_rowidx, e_sign, e_use_new, e_w, e_t, e_mask, touch_rows, touch_mask) = rec
 
         # ---- constrained full path ----
-        f_srcs, f_ridx, f_ws, f_ts = [], [], [], []
-        for ri, v in enumerate(v_full):
-            nbrs, ws, ts = g_new.in_edge_data(int(v))
-            f_srcs.extend(nbrs.tolist())
-            f_ridx.extend([ri] * nbrs.shape[0])
-            f_ws.extend(ws.tolist())
-            f_ts.extend(ts.tolist())
+        fpos, f_ridx = _segments(g_new.in_indptr, v_full)
+        f_srcs = g_new.in_indices[fpos]
         f_cap = next_bucket(v_full.shape[0])
-        fe_cap = next_bucket(len(f_srcs))
+        fe_cap = next_bucket(f_srcs.shape[0])
 
         def padv(a, cap, fill, dt):
             out = np.full(cap, fill, dtype=dt)
@@ -282,21 +283,25 @@ def build_plan(
         f_mask = padv(np.ones(v_full.shape[0], bool), f_cap, False, bool)
         f_src = padv(f_srcs, fe_cap, n, np.int32)
         f_rowidx = padv(f_ridx, fe_cap, f_cap, np.int32)
-        f_w = padv(f_ws, fe_cap, 0.0, np.float32)
-        f_t = padv(f_ts, fe_cap, 0, np.int32)
-        f_emask = padv(np.ones(len(f_srcs), bool), fe_cap, False, bool)
+        f_w = padv(g_new.in_weights[fpos], fe_cap, 0.0, np.float32)
+        f_t = padv(g_new.in_etypes[fpos], fe_cap, 0, np.int32)
+        f_emask = padv(np.ones(f_srcs.shape[0], bool), fe_cap, False, bool)
 
         # ---- output rows ----
-        out_set = set(touch_rows[touch_mask].tolist()) | v_full_set
+        out_set = np.zeros(n, bool)
+        out_set[touch_rows[touch_mask]] = True
+        out_set[v_full] = True
         if model.update_uses_h:
-            out_set |= changed_set if allowed is None else (changed_set & allowed)
-        out = np.array(sorted(out_set), np.int64)
+            out_set |= changed if allowed is None else changed & allowed
+        out = np.nonzero(out_set)[0]
         o_cap = next_bucket(out.shape[0])
         out_rows = padv(out, o_cap, n, np.int32)
         out_mask = padv(np.ones(out.shape[0], bool), o_cap, False, bool)
 
         n_inc = ins_s.shape[0] + del_s.shape[0] + n_changed_edges
-        srcs_accessed = len(set(rs) | set(f_srcs))
+        accessed = np.zeros(n, bool)
+        accessed[src] = True
+        accessed[f_srcs] = True
         plans.append(
             LayerPlan(
                 e_src=e_src,
@@ -319,14 +324,14 @@ def build_plan(
                 out_rows=out_rows,
                 out_mask=out_mask,
                 n_inc_edges=n_inc,
-                n_full_edges=len(f_srcs),
+                n_full_edges=int(f_srcs.shape[0]),
                 n_touch_rows=int(touch_mask.sum()),
                 n_full_rows=int(v_full.shape[0]),
                 n_out_rows=int(out.shape[0]),
-                n_src_accessed=srcs_accessed,
+                n_src_accessed=int(accessed.sum()),
             )
         )
-        changed_h = out
+        changed = out_set
 
     deg_old_x = np.concatenate([deg_old, np.zeros(1, np.float32)])
     deg_new_x = np.concatenate([deg_new, np.zeros(1, np.float32)])
